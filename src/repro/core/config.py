@@ -199,6 +199,14 @@ class ForgeConfig:
             raise ValueError(
                 f"unknown execution_backend {self.execution_backend!r}; "
                 f"choose one of {sorted(EXECUTION_BACKENDS)}")
+        if self.execution_backend in ("process", "remote"):
+            import jax
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"execution_backend={self.execution_backend!r} spawns "
+                    f"JAX worker processes, but a TPU belongs to one process "
+                    f"at a time and this one holds it; use 'serial' or "
+                    f"'thread' on a TPU")
         if self.verify_fastpath not in VERIFY_FASTPATH_MODES:
             raise ValueError(
                 f"unknown verify_fastpath {self.verify_fastpath!r}; "

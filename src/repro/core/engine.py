@@ -186,6 +186,8 @@ class VerifyStats:
     deferred_runs: int = 0
     shared_group_hits: int = 0      # group execs served by the shared layer
     shared_oracle_hits: int = 0     # oracle preps rebound from it
+    exec_passed: int = 0            # correctness runs that matched the oracle
+    exec_errors: int = 0            # correctness runs that raised
     planner_signatures: int = 0     # duplicated slices the planner executed
     planner_deduped_jobs: int = 0   # follower jobs that started warm
     planner_group_execs: int = 0    # group execs the planner paid up front

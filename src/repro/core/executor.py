@@ -6,7 +6,8 @@ weight seeding and dispatch (the paper's *kernel harness separation*, §VII-a):
 a candidate is only a (graph, schedule) value; it cannot route execution back
 to the oracle or touch the harness.
 
-Pallas-impl groups are executed through the real kernels in interpret mode;
+Pallas-impl groups are executed through the real kernels: compiled by Mosaic
+on a TPU, in the Pallas interpreter elsewhere (``repro.kernels.platform``);
 XLA-impl groups evaluate node-by-node with jnp. Mixed precision follows the
 TPU pattern: external group inputs are stored/loaded in the schedule's
 compute dtype, math runs in f32 (MXU: bf16 in, f32 accumulate).
@@ -118,7 +119,7 @@ def _as_epilogue(graph: Graph, nodes: List[Node], produced: set,
 
 
 def _run_pallas_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarray],
-                      compute_dtype, interpret: bool = True) -> Dict[str, jnp.ndarray]:
+                      compute_dtype) -> Dict[str, jnp.ndarray]:
     nodes = [graph.node(n) for n in group.nodes]
     produced = set(group.nodes)
     cfg = group.config
@@ -133,8 +134,8 @@ def _run_pallas_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarr
         x = load(n.inputs[0])
         w = env[n.inputs[1]] if len(n.inputs) > 1 else jnp.ones(x.shape[-1], x.dtype)
         lead, d = x.shape[:-1], x.shape[-1]
-        out = rmsnorm_kernel(x.reshape(-1, d), w, eps=n.attrs.get("eps", 1e-6),
-                             interpret=interpret).reshape(*lead, d)
+        out = rmsnorm_kernel(x.reshape(-1, d), w,
+                             eps=n.attrs.get("eps", 1e-6)).reshape(*lead, d)
         return {n.name: out}
 
     # template 2: matmul (+ epilogue chain) (+ terminal row reduction)
@@ -165,8 +166,7 @@ def _run_pallas_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarr
             bk = min(cfg.block_k if cfg else 128, k)
             out = matmul_fused_naive(a, b, block_m=bm, block_n=bn, block_k=bk,
                                      epilogue=epilogue, operands=operands,
-                                     reduction=reduction, out_dtype=compute_dtype,
-                                     interpret=interpret)
+                                     reduction=reduction, out_dtype=compute_dtype)
         else:
             c = cfg or type("C", (), {})()
             out = matmul_fused(
@@ -177,7 +177,7 @@ def _run_pallas_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarr
                 group_m=getattr(c, "group_m", 1),
                 num_stages=getattr(c, "num_stages", 2),
                 epilogue=epilogue, operands=operands, reduction=reduction,
-                out_dtype=compute_dtype, interpret=interpret)
+                out_dtype=compute_dtype)
         last = group.nodes[-1]
         want_shape = graph.node(last).shape
         return {last: out.reshape(want_shape)}
@@ -192,7 +192,7 @@ def _run_pallas_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarr
                     if env[s].ndim == x.ndim else env[s].astype(compute_dtype)
                     for s in op_names}
         out = elementwise_chain(x.reshape(-1, ccol), epilogue, operands=operands,
-                                out_dtype=compute_dtype, interpret=interpret)
+                                out_dtype=compute_dtype)
         last = group.nodes[-1]
         return {last: out.reshape(graph.node(last).shape)}
 
@@ -224,14 +224,13 @@ def _run_xla_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarray]
 
 # ----------------------------------------------------------------------
 def run_group(graph: Graph, group: FusionGroup, env: Dict[str, jnp.ndarray],
-              compute_dtype, use_pallas: bool = True,
-              interpret: bool = True) -> Dict[str, jnp.ndarray]:
+              compute_dtype, use_pallas: bool = True) -> Dict[str, jnp.ndarray]:
     """Execute one fusion group against ``env`` — the single dispatch point
     shared by :func:`run_program` and the memoizing fast path
     (``repro.core.verify_cache``). Returns the name->array bindings the
     group contributes (pallas templates emit only their final node)."""
     if group.impl.startswith("pallas") and use_pallas:
-        return _run_pallas_group(graph, group, env, compute_dtype, interpret)
+        return _run_pallas_group(graph, group, env, compute_dtype)
     return _run_xla_group(graph, group, env, compute_dtype)
 
 
@@ -286,8 +285,7 @@ def group_exec_signature(graph: Graph, group: FusionGroup,
 def run_program(program: KernelProgram,
                 inputs: Dict[str, jnp.ndarray],
                 params: Dict[str, jnp.ndarray],
-                use_pallas: bool = True,
-                interpret: bool = True) -> Dict[str, jnp.ndarray]:
+                use_pallas: bool = True) -> Dict[str, jnp.ndarray]:
     graph = program.graph
     sched = program.schedule
     compute_dtype = jnp.dtype(sched.compute_dtype)
@@ -301,7 +299,7 @@ def run_program(program: KernelProgram,
             env[n.name] = jnp.asarray(n.attrs["value"], jnp.dtype(n.dtype))
     for g in group_order(graph, sched.groups):
         env.update(run_group(graph, g, env, compute_dtype,
-                             use_pallas=use_pallas, interpret=interpret))
+                             use_pallas=use_pallas))
     return {o: env[o].astype(jnp.float32) for o in graph.outputs}
 
 

@@ -8,10 +8,11 @@ becomes the agent's observation for the next refinement iteration:
   2. Structure   — KB hardware constraints hold (block alignment, VMEM budget,
                    MXU minimums, f32 accumulation, dtype bans, ...); messages
                    carry remediation instructions, paper-style.
-  3. Correctness — executed (real Pallas kernels, interpret mode) against the
-                   seeded oracle outputs; allclose(rtol, atol) + NaN/Inf gates;
-                   mismatch diagnostics include max-abs/mean/rel-diff and
-                   exceed counts plus likely causes.
+  3. Correctness — executed (real Pallas kernels: compiled on a TPU, the
+                   interpreter elsewhere) against the seeded oracle
+                   outputs; allclose(rtol, atol) + NaN/Inf gates; mismatch
+                   diagnostics include max-abs/mean/rel-diff and exceed
+                   counts plus likely causes.
   4. Performance — the v5e roofline cost model must beat the incumbent. On
                    failure the agent receives both timings + TFLOPS +
                    alternative-strategy suggestions.
@@ -223,6 +224,8 @@ def run_correctness(candidate_ci: KernelProgram,
                             f"NO KERNEL TEMPLATE: {e}. Fix: keep the group "
                             f"as impl='xla' or restructure the fusion.")
     except Exception as e:  # noqa: BLE001
+        if session is not None:
+            session.stats.exec_errors += 1
         return VerifyReport(False, "correctness",
                             f"RUNTIME ERROR during execution: "
                             f"{type(e).__name__}: {e}")
@@ -251,6 +254,8 @@ def run_correctness(candidate_ci: KernelProgram,
                 False, "correctness",
                 f"OUTPUT MISMATCH on {key} (rtol={ctx.rtol}, atol={ctx.atol}): "
                 + _diff_diagnostics(gval, want, ctx.rtol, ctx.atol))
+    if session is not None:
+        session.stats.exec_passed += 1
     return None
 
 

@@ -78,6 +78,7 @@ from repro.ir.fingerprint import (content_leaf_fingerprint,
                                   program_exact_fingerprint,
                                   trace_fingerprint)
 from repro.ir.schedule import KernelProgram
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["VerifySession", "VerifySessionStats", "VerifyFastpathDivergence",
            "SharedVerifyCache", "VERIFY_FASTPATH_MODES", "run_program_cached"]
@@ -311,6 +312,8 @@ class VerifySessionStats:
     deferred_runs: int = 0      # deferred correctness lazily executed
     shared_group_hits: int = 0  # group executions served by the shared layer
     shared_oracle_hits: int = 0  # oracle preps rebound from the shared layer
+    exec_passed: int = 0        # correctness runs that matched the oracle
+    exec_errors: int = 0        # correctness runs that raised (RUNTIME ERROR)
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -504,8 +507,7 @@ def run_program_cached(program: KernelProgram,
                        inputs: Dict[str, jnp.ndarray],
                        params: Dict[str, jnp.ndarray],
                        session: VerifySession,
-                       use_pallas: bool = True,
-                       interpret: bool = True) -> Dict[str, jnp.ndarray]:
+                       use_pallas: bool = True) -> Dict[str, jnp.ndarray]:
     """Drop-in for :func:`repro.core.executor.run_program` that replays
     memoized group executions. Produces bit-identical results by
     construction: a group either re-executes through the exact same
@@ -537,13 +539,13 @@ def run_program_cached(program: KernelProgram,
         sig = group_exec_signature(graph, g, use_pallas=use_pallas)
         gfp = group_fingerprint(graph, g, value_fps,
                                 extra=[sig, sched.compute_dtype,
-                                       bool(interpret)])
+                                       resolve_interpret()])
         positions = {name: i for i, name in enumerate(g.nodes)}
         cached = session._get_group(gfp)
         if cached is None:
             def _validate(entry, _g=g, _gfp=gfp):
                 ref = run_group(graph, _g, env, compute_dtype,
-                                use_pallas=use_pallas, interpret=interpret)
+                                use_pallas=use_pallas)
                 want = {_g.nodes[i]: v for i, v in entry}
                 if set(want) != set(ref):
                     raise VerifyFastpathDivergence(
@@ -556,7 +558,7 @@ def run_program_cached(program: KernelProgram,
             cached = session._get_group_shared(gfp, validate=_validate)
         if cached is None:
             out = run_group(graph, g, env, compute_dtype,
-                            use_pallas=use_pallas, interpret=interpret)
+                            use_pallas=use_pallas)
             entry = [(positions[k], v) for k, v in out.items()]
             session._put_group(gfp, entry)
             if session._shared is not None:

@@ -1,10 +1,11 @@
 """TPU hardware specifications.
 
 The paper's GPU hardware query system (Xe-Forge IV-E) reads device properties at
-runtime (EU count, SLM capacity, GRF modes, ...). On TPU there is no runtime to
-query in this container, so the spec table *is* the detection path: ``get_spec``
-maps a generation name to a :class:`TPUSpec`, exactly the role of
-``torch.xpu.get_device_properties`` + family defaults in the paper.
+runtime (EU count, SLM capacity, GRF modes, ...). On a TPU the runtime reports
+only ``device_kind``, so the spec table is the detection path:
+``spec_for_device`` maps the attached chip's kind to a :class:`TPUSpec` and
+refuses a kind it does not know; ``get_spec`` maps a generation name (the
+optimization target a ``ForgeConfig`` names) to one.
 
 All constants are per-chip (one TensorCore exposed per v5e chip). The roofline
 constants used by the assignment are the v5e ones: 197 bf16 TFLOP/s, 819 GB/s
@@ -105,6 +106,31 @@ TPU_V5P = TPUSpec(
 
 _SPECS: Dict[str, TPUSpec] = {s.name: s for s in (TPU_V5E, TPU_V4, TPU_V5P)}
 _SPECS.update({"v5e": TPU_V5E, "v4": TPU_V4, "v5p": TPU_V5P})
+
+
+# ``jax.Device.device_kind`` -> spec. Peaks: Google Cloud TPU documentation
+# ("TPU v5e", "TPU v4", "TPU v5p" system architecture pages).
+_KINDS: Dict[str, TPUSpec] = {"TPU v5 lite": TPU_V5E, "TPU v4": TPU_V4,
+                              "TPU v5": TPU_V5P}
+
+
+def spec_for_kind(kind: str) -> TPUSpec:
+    try:
+        return _KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no spec for device_kind {kind!r}; known: "
+                       f"{sorted(_KINDS)}") from None
+
+
+def spec_for_device(device=None) -> TPUSpec:
+    """The spec of ``device`` (default: the first JAX device), which must be
+    a TPU of a known kind — never a default."""
+    import jax
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "tpu":
+        raise RuntimeError(f"no TPU attached: the first device is "
+                           f"{device.platform!r} ({device.device_kind})")
+    return spec_for_kind(device.device_kind)
 
 
 def get_spec(name: str = "tpu_v5e") -> TPUSpec:

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import CompilerParams
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -31,7 +32,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                      scale: Optional[float] = None,
                      block_kv: int = 512,
                      acc_dtype=jnp.float32,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
     b, h, d = q.shape
     _, hkv, s, _ = k.shape
     assert h % hkv == 0
@@ -41,7 +42,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kt = _cdiv(s, block_kv)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
-    lengths2d = lengths.reshape(b, 1).astype(jnp.int32)
+    # per-row valid lengths ride in SMEM as scalar prefetch: a (1, 1) VMEM
+    # block of a (B, 1) array is neither the full array nor tile-aligned
+    lengths = lengths.reshape(b).astype(jnp.int32)
 
     # group q heads that share a kv head into one tile: [B*Hkv, q_per_kv, D]
     qf = q.reshape(b, hkv, q_per_kv, d).reshape(b * hkv, q_per_kv, d)
@@ -62,7 +65,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         st = jax.lax.dot_general(qv, kv_, (((1,), (1,)), ((), ())),
                                  preferred_element_type=acc_dtype) * scale
         kpos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        valid = kpos < len_ref[0, 0]
+        valid = kpos < len_ref[pl.program_id(0) // hkv]
         st = jnp.where(valid, st, NEG_INF)
 
         m_prev = m_ref[...]
@@ -82,15 +85,15 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
+        num_scalar_prefetch=1,
         grid=(b * hkv, kt),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, kj: (bh // hkv, 0)),
-            pl.BlockSpec((1, q_per_kv, d), lambda bh, kj: (bh, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, q_per_kv, d), lambda bh, kj, lens: (bh, 0, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda bh, kj, lens: (bh, kj, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda bh, kj, lens: (bh, kj, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_per_kv, d), lambda bh, kj: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((1, q_per_kv, d),
+                               lambda bh, kj, lens: (bh, 0, 0)),
         scratch_shapes=[pltpu.VMEM((q_per_kv, 1), acc_dtype),
                         pltpu.VMEM((q_per_kv, 1), acc_dtype),
                         pltpu.VMEM((q_per_kv, d), acc_dtype)],
@@ -99,8 +102,8 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, q_per_kv, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths2d, qf, kf, vf)
+        interpret=resolve_interpret(interpret),
+    )(lengths, qf, kf, vf)
     return out.reshape(b, h, d)

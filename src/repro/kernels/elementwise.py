@@ -12,10 +12,11 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.epilogue import EpilogueOp, apply_epilogue
 from repro.kernels.matmul_fused import _normalize_operand, _operand_spec
+from repro.kernels.platform import resolve_interpret
 
 
 def _cdiv(a, b):
@@ -26,7 +27,7 @@ def elementwise_chain(x: jnp.ndarray, epilogue: List[EpilogueOp], *,
                       operands: Optional[Dict[str, jnp.ndarray]] = None,
                       block_rows: int = 256,
                       out_dtype=None,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
     operands = operands or {}
     r, c = x.shape
     out_dtype = out_dtype or x.dtype
@@ -54,6 +55,6 @@ def elementwise_chain(x: jnp.ndarray, epilogue: List[EpilogueOp], *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), out_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=resolve_interpret(interpret),
     )(x, *[norm_ops[s] for s in op_names])

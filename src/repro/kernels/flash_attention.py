@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import CompilerParams
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -36,7 +37,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_kv: int = 128,
                     acc_dtype=jnp.float32,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     assert h % hkv == 0, (h, hkv)
@@ -108,9 +109,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((block_q, 1), acc_dtype),
                         pltpu.VMEM((block_q, 1), acc_dtype),
                         pltpu.VMEM((block_q, d), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)
 
@@ -119,7 +120,7 @@ def attention_unoptimized(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                           causal: bool = False,
                           scale: Optional[float] = None,
                           block_q: int = 128,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: Optional[bool] = None) -> jnp.ndarray:
     """The KernelFalcon-style starting point: full-KV per q-tile, full score
     materialization, single-pass softmax. O(Skv) VMEM per program."""
     b, h, sq, d = q.shape
@@ -162,6 +163,6 @@ def attention_unoptimized(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

@@ -3,8 +3,8 @@
 Two implementations, matching the pipeline's before/after axis:
 
 * :func:`matmul_fused_naive` — "manual pointer arithmetic": whole-array refs,
-  flat output grid, explicit ``pl.load``/``pl.ds`` tile indexing, serial K
-  loop in the body. Mosaic gets no BlockSpecs, so nothing is pipelined. This
+  flat output grid, explicit ``ref[pl.ds(..), pl.ds(..)]`` tile indexing,
+  serial K loop in the body. Mosaic gets no BlockSpecs, so nothing is pipelined. This
   is the Triton-without-``make_block_ptr`` analogue the block-pointer stage
   modernizes away.
 
@@ -24,10 +24,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import CompilerParams
 
 from repro.kernels.epilogue import (EpilogueOp, apply_epilogue, reduce_combine,
                                     reduce_init, reduce_tile)
+from repro.kernels.platform import resolve_interpret
+
+# scoped-VMEM room above the naive kernel's resident arrays (Mosaic's own
+# scratch, the register-spilled accumulator)
+_VMEM_HEADROOM = 16 * 2**20
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -87,7 +91,7 @@ def matmul_fused(a: jnp.ndarray, b: jnp.ndarray, *,
                  acc_dtype=jnp.float32,
                  out_dtype=None,
                  dimension_semantics: Tuple[str, ...] = ("parallel", "arbitrary"),
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """C = epilogue(A @ B) [optionally reduced over N]. A: [M,K], B: [K,N]."""
     epilogue = epilogue or []
     operands = operands or {}
@@ -100,6 +104,7 @@ def matmul_fused(a: jnp.ndarray, b: jnp.ndarray, *,
     op_names = sorted({e.operand for e in epilogue if e.operand is not None})
     norm_ops = {s: _normalize_operand(s, operands[s], m, n) for s in op_names}
 
+    interpret = resolve_interpret(interpret)
     if reduction is None:
         return _matmul_epilogue_swizzled(
             a, b, norm_ops, op_names, epilogue, m, n, k,
@@ -157,7 +162,7 @@ def _matmul_epilogue_swizzled(a, b, norm_ops, op_names, epilogue, m, n, k,
         out_specs=pl.BlockSpec((bm, bn), lambda p, kk: (m_of(p, kk), n_of(p, kk))),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(dimension_semantics)[:2] or ("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, *[norm_ops[s] for s in op_names])
@@ -227,7 +232,7 @@ def _matmul_reduce(a, b, norm_ops, op_names, epilogue, reduction, m, n, k,
         out_shape=jax.ShapeDtypeStruct((m, 1), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype),
                         pltpu.VMEM((bm, 1), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a, b, *[norm_ops[s] for s in op_names])
@@ -244,11 +249,11 @@ def matmul_fused_naive(a: jnp.ndarray, b: jnp.ndarray, *,
                        operands: Optional[Dict[str, jnp.ndarray]] = None,
                        reduction: Optional[str] = None,
                        out_dtype=None,
-                       interpret: bool = True) -> jnp.ndarray:
-    """The 'unoptimized input kernel': flat grid, manual tile indexing via
-    pl.load/pl.ds over whole-array refs, bf16-unsafe f32 accumulation in
-    registers, no masking, no pipelining. Requires divisible shapes (the
-    missing_boundary_check issue, on purpose)."""
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
+    """The 'unoptimized input kernel': flat grid, manual tile indexing
+    (``ref[pl.ds(..), pl.ds(..)]``) over whole arrays held in VMEM, f32
+    accumulation in registers, no masking, no pipelining. Requires divisible
+    shapes (the missing_boundary_check issue, on purpose)."""
     epilogue = epilogue or []
     operands = operands or {}
     m, k = a.shape
@@ -268,49 +273,63 @@ def matmul_fused_naive(a: jnp.ndarray, b: jnp.ndarray, *,
         o_ref = rest[len(op_names)]
         p = pl.program_id(0)
         mi, ni = p // nt, p % nt
-        row0, col0 = mi * block_m, ni * block_n
+        rows = pl.ds(pl.multiple_of(mi * block_m, block_m), block_m)
+        cols = pl.ds(pl.multiple_of(ni * block_n, block_n), block_n)
 
-        def body(kk, acc):
-            a_tile = pl.load(a_ref, (pl.ds(row0, block_m), pl.ds(kk * block_k, block_k)))
-            b_tile = pl.load(b_ref, (pl.ds(kk * block_k, block_k), pl.ds(col0, block_n)))
-            return acc + jnp.dot(a_tile, b_tile, preferred_element_type=jnp.float32)
+        def k_step(acc, k0):
+            ks = pl.ds(k0, block_k)
+            return acc + jnp.dot(a_ref[rows, ks], b_ref[ks, cols],
+                                 preferred_element_type=jnp.float32)
 
-        acc = jax.lax.fori_loop(0, kt, body,
-                                jnp.zeros((block_m, block_n), jnp.float32))
+        acc = jnp.zeros((block_m, block_n), jnp.float32)
+        if block_k % 128 == 0:
+            acc = jax.lax.fori_loop(0, kt, lambda kk, acc: k_step(
+                acc, pl.multiple_of(kk * block_k, block_k)), acc)
+        else:
+            # Mosaic proves a dynamic lane offset aligned only when it is a
+            # multiple of 128; unaligned K tiles (the GPU-default 32) take
+            # static offsets instead
+            for kk in range(kt):
+                acc = k_step(acc, kk * block_k)
         tile_ops = {}
         for s, r in zip(op_names, op_refs):
             arr = norm_ops[s]
-            rsel = pl.ds(row0, block_m) if arr.shape[0] != 1 else pl.ds(0, 1)
-            csel = pl.ds(col0, block_n) if arr.shape[1] != 1 else pl.ds(0, 1)
-            tile_ops[s] = pl.load(r, (rsel, csel))
+            rsel = rows if arr.shape[0] != 1 else pl.ds(0, 1)
+            csel = cols if arr.shape[1] != 1 else pl.ds(0, 1)
+            tile_ops[s] = r[rsel, csel]
         tile = apply_epilogue(acc, epilogue, tile_ops)
         if reduction is None:
-            pl.store(o_ref, (pl.ds(row0, block_m), pl.ds(col0, block_n)),
-                     tile.astype(o_ref.dtype))
+            o_ref[rows, cols] = tile.astype(o_ref.dtype)
         else:
             part = reduce_tile(tile, reduction, axis=-1, keepdims=True)
             if reduction == "mean":
                 part = part / n
             # every n-tile accumulates into the same column: serialized, racy
             # unless the grid is sequential — which on TPU it is (no swizzle).
-            prev = pl.load(o_ref, (pl.ds(row0, block_m), pl.ds(0, 1)))
+            prev = o_ref[rows, pl.ds(0, 1)]
             init = jnp.asarray(reduce_init(reduction), jnp.float32)
             prev = jnp.where(ni == 0, jnp.full_like(prev, init.astype(prev.dtype)), prev)
             comb = reduce_combine(prev.astype(jnp.float32), part, reduction)
-            pl.store(o_ref, (pl.ds(row0, block_m), pl.ds(0, 1)),
-                     comb.astype(o_ref.dtype))
+            o_ref[rows, pl.ds(0, 1)] = comb.astype(o_ref.dtype)
 
     out_shape = (m, n) if reduction is None else (m, 1)
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda p: (0,) * arr.ndim)
-    in_specs = [full(a), full(b)] + [full(norm_ops[s]) for s in op_names]
+    args = [a, b] + [norm_ops[s] for s in op_names]
+    # whole arrays live in VMEM for the kernel's lifetime (no blocking, so
+    # no double buffering); the scoped limit is raised to hold them
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    resident = (sum(x.size * x.dtype.itemsize for x in args)
+                + out_shape[0] * out_shape[1] * jnp.dtype(out_dtype).itemsize)
     out = pl.pallas_call(
         kernel,
         grid=(mt * nt,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(out_shape, lambda p: (0, 0)),
+        in_specs=[whole] * len(args),
+        out_specs=whole,
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
-        interpret=interpret,
-    )(a, b, *[norm_ops[s] for s in op_names])
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=resident + _VMEM_HEADROOM),
+        interpret=resolve_interpret(interpret),
+    )(*args)
     if reduction is not None:
         # mean already rescaled in-kernel
         return out[:, 0]

@@ -6,10 +6,14 @@ One read, one write; f32 math regardless of io dtype.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _cdiv(a, b):
@@ -17,7 +21,8 @@ def _cdiv(a, b):
 
 
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
+            block_rows: int = 256,
+            interpret: Optional[bool] = None) -> jnp.ndarray:
     r, d = x.shape
     block_rows = min(block_rows, r)
     rt = _cdiv(r, block_rows)
@@ -35,6 +40,6 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=resolve_interpret(interpret),
     )(x, w.reshape(1, d))

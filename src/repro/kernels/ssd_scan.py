@@ -17,17 +17,20 @@ Returns y [BH, L, P], final_state [BH, P, N].
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.compat import CompilerParams
+
+from repro.kernels.platform import resolve_interpret
 
 
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
              b: jnp.ndarray, c: jnp.ndarray, *,
              chunk: int = 128,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     bh, l, p = x.shape
     n = b.shape[-1]
     chunk = min(chunk, l)
@@ -98,8 +101,8 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
             jax.ShapeDtypeStruct((bh, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, a, b, c)
     return y, s

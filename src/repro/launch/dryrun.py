@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_DRYRUN_XLA_FLAGS")
                            or "--xla_force_host_platform_device_count=512")
+# a compile study on host devices, never a chip run: pinning the CPU keeps
+# every dry-run process (dryrun_all fans out one per cell) off the TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell on the
 production mesh without allocating a single weight.
 
-MUST set XLA_FLAGS before ANY other import (jax locks the device count on
-first init) — hence the module-top assignment above.
+MUST set XLA_FLAGS and JAX_PLATFORMS before ANY other import (jax locks the
+device count and platform on first init) — hence the module-top assignments.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-8b \

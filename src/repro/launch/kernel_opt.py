@@ -17,12 +17,10 @@ from typing import Dict, List, Tuple
 from repro.configs.base import ModelConfig
 from repro.core.engine import KernelJob
 from repro.forge import Forge, ForgeConfig
-from repro.hw.query import HardwareQuery
-from repro.hw.specs import TPU_V5E
 from repro.ir.cost import graph_flops
 from repro.ir.graph import GraphBuilder
 from repro.ir.schedule import KernelProgram, PallasConfig, eager_schedule
-from repro.kernels.ops import REGISTRY, _sig
+from repro.kernels.ops import REGISTRY, _query, _sig
 
 
 def matmul_sites(cfg: ModelConfig, seq_len: int, batch: int
@@ -102,10 +100,10 @@ def optimize_arch_kernels(cfg: ModelConfig, seq_len: int = 4096,
                          "dims": [m, n, k], "cache_hit": eres.cache_hit,
                          "transfer": eres.transfer,
                          "seed_steps": eres.seed_steps}
-    # attention sites straight from the hardware query (the pipeline's
-    # gpu-specific stage delegates attention tiling to it)
-    hw = HardwareQuery(TPU_V5E)
-    ap = hw.get_attention_params(seq_len, seq_len, cfg.resolved_head_dim or 128)
+    # attention sites straight from the hardware query of the chip the
+    # kernels compile for (the pipeline's gpu-specific stage delegates
+    # attention tiling to it)
+    ap = _query().get_attention_params(seq_len, seq_len, cfg.resolved_head_dim or 128)
     REGISTRY.put("flash_attention",
                  _sig(seq_len, seq_len, cfg.resolved_head_dim or 128, "bfloat16"),
                  {"block_q": ap.block_m, "block_kv": ap.block_n})

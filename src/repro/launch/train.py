@@ -16,6 +16,7 @@ import time
 
 
 from repro.configs.base import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import RuntimeFlags
 from repro.optim.adamw import AdamWConfig
 from repro.train.train_step import TrainConfig
@@ -38,6 +39,7 @@ def main():
                     choices=["off", "cached", "run"])
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

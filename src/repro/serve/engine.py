@@ -2,16 +2,17 @@
 
 Admission is batch-synchronous (a wave of equal-length prompts fills the
 slots, decodes in lockstep, then the next wave admits) — the slot/cache
-plumbing that a continuous-batching scheduler would drive; the multi-pod
+plumbing that a continuous-batching scheduler would drive. The KV cache is
+kept in the parameters' dtype (bf16 at published width); the multi-pod
 serving path (sharded caches, split-KV decode) is exercised by the dry-run
-cells rather than this CPU-scale engine.
+cells rather than this single-device engine.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Deque, List
+from typing import Deque, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,8 @@ class Request:
     max_new_tokens: int = 16
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # float32 logits after the last prompt token (they pick generated[0])
+    prompt_logits: Optional[np.ndarray] = None
 
 
 class ServeEngine:
@@ -59,7 +62,8 @@ class ServeEngine:
         plen = len(wave[0].prompt)
         assert all(len(r.prompt) == plen for r in wave), \
             "wave admission requires equal-length prompts"
-        cache = init_cache(self.cfg, self.slots, self.max_len, jnp.float32)
+        cache = init_cache(self.cfg, self.slots, self.max_len,
+                           self.params["embed"].dtype)
         # prefill via lockstep decode steps (slot-batched)
         logits = None
         for t in range(plen):
@@ -71,7 +75,10 @@ class ServeEngine:
         # greedy decode
         pos = plen
         alive = list(range(len(wave)))
-        nxt = np.argmax(np.asarray(logits), axis=-1)
+        logits = np.asarray(logits)
+        for i, r in enumerate(wave):
+            r.prompt_logits = logits[i]
+        nxt = np.argmax(logits, axis=-1)
         while alive and pos < self.max_len:
             toks = np.zeros((self.slots, 1), np.int32)
             for i in alive:

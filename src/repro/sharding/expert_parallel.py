@@ -17,7 +17,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.compat import shard_map
 
 
 def apply_moe_ep(cfg: ModelConfig, p: Dict, x: jnp.ndarray, mesh: Mesh,
@@ -61,7 +60,7 @@ def apply_moe_ep(cfg: ModelConfig, p: Dict, x: jnp.ndarray, mesh: Mesh,
              * gates.astype(out.dtype)[..., None]).sum(axis=1)
         return y.reshape(b, s, d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis), P()),
         out_specs=P(),

@@ -18,7 +18,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -65,7 +64,7 @@ def cross_pod_mean(grads: Any, err: Any, mesh: Mesh, axis: str = "pod"):
     flat_e = jax.tree_util.tree_leaves(err)
 
     out_g, out_e = [], []
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda g, e: per_shard(g, e),
         mesh=mesh,
         in_specs=(P(), P()),

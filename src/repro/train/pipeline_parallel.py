@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 
 def pipeline_forward(mesh: Mesh, stage_fn: Callable, num_stages: int,
@@ -71,7 +70,7 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, num_stages: int,
             "pipe")
         return outputs
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P("pipe"), P()),
         out_specs=P(),

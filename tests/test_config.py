@@ -142,6 +142,15 @@ def test_validation():
     assert ForgeConfig(stages_enabled=["fusion"]).stages_enabled == ("fusion",)
 
 
+@pytest.mark.parametrize("backend", ["process", "remote"])
+def test_spawning_backends_refused_on_a_tpu(backend, monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="one process at a time"):
+        ForgeConfig(execution_backend=backend)
+    assert ForgeConfig(execution_backend="thread").execution_backend == "thread"
+
+
 # ---------------------------------------------------------------------------
 # compatibility shims
 # ---------------------------------------------------------------------------

@@ -65,9 +65,9 @@ def test_grad_compression_int8_ef():
     across steps."""
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.train.grad_compress import cross_pod_mean, compression_ratio
-        mesh = make_test_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)}
         err = jax.tree.map(jnp.zeros_like, g)
@@ -89,11 +89,11 @@ def test_expert_parallel_matches_dense():
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_config
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.layers import apply_moe, init_moe
         from repro.sharding.expert_parallel import apply_moe_ep
         cfg = get_config("grok-1-314b").reduced()  # 4 experts
-        mesh = make_test_mesh((4,), ("expert",))
+        mesh = make_mesh((4,), ("expert",))
         p = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
         dense = apply_moe(cfg, p, x, capacity_factor=8.0)
@@ -110,10 +110,10 @@ def test_expert_parallel_matches_dense():
 def test_pipeline_parallel_matches_reference():
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.train.pipeline_parallel import (init_mlp_pipeline,
             mlp_stage_fn, pipeline_forward, reference_forward)
-        mesh = make_test_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         params = init_mlp_pipeline(jax.random.PRNGKey(0), 4, 16, 32)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 16))  # 8 microbatches
         fn = pipeline_forward(mesh, mlp_stage_fn, 4, 8)
@@ -132,7 +132,7 @@ def test_sharded_train_step_runs_and_matches_single():
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_config
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.model import RuntimeFlags, init_params
         from repro.optim import adamw
         from repro.sharding import rules
@@ -146,7 +146,7 @@ def test_sharded_train_step_runs_and_matches_single():
         step = make_train_step(cfg, flags, TrainConfig())
         p0, o0, m0 = jax.jit(step)(params, opt, batch)   # single device
 
-        mesh = make_test_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         shp = rules.shard_params(params, mesh)
         params_s = jax.device_put(params, shp)
         opt_s = adamw.OptState(
@@ -176,12 +176,12 @@ def test_elastic_checkpoint_across_device_counts(tmp_path):
         import jax, jax.numpy as jnp
         from repro.ckpt.checkpoint import CheckpointManager
         from repro.configs.base import get_config
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.model import init_params
         from repro.sharding import rules
         cfg = get_config("olmo-1b").reduced()
         params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-        mesh = make_test_mesh((DEV, 1), ("data", "model"))
+        mesh = make_mesh((DEV, 1), ("data", "model"))
         params = jax.device_put(params, rules.shard_params(params, mesh))
         mgr = CheckpointManager(r"{tmp_path}")
         STEP

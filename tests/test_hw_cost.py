@@ -5,7 +5,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.hw.query import HardwareQuery
-from repro.hw.specs import TPU_V5E, dtype_itemsize, get_spec
+from repro.hw.specs import (TPU_V5E, dtype_itemsize, get_spec,
+                             spec_for_device, spec_for_kind)
 from repro.ir import GraphBuilder
 from repro.ir.cost import CostModel, graph_flops
 from repro.ir.schedule import (FusionGroup, KernelProgram, PallasConfig,
@@ -127,3 +128,14 @@ def test_specs_table():
     assert get_spec("tpu_v5e").hbm_bw == pytest.approx(819e9)
     with pytest.raises(KeyError):
         get_spec("h100")
+
+
+def test_spec_resolved_from_device_kind():
+    assert spec_for_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no spec for device_kind"):
+        spec_for_kind("TPU v9 hyper")
+
+
+def test_spec_for_device_refuses_a_cpu():
+    with pytest.raises(RuntimeError, match="no TPU attached"):
+        spec_for_device()

@@ -161,3 +161,11 @@ def test_ssd_scan_property(l, chunk, seed):
                                rtol=5e-4, atol=5e-4)
     np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
                                rtol=5e-4, atol=5e-4)
+
+
+def test_interpret_resolved_from_the_backend(monkeypatch):
+    from repro.kernels.platform import resolve_interpret
+    assert resolve_interpret() is True            # the CPU backend
+    assert resolve_interpret(False) is False      # explicit wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False

@@ -63,6 +63,39 @@ def test_serve_engine_generates():
     assert again[0].generated == done[0].generated
 
 
+def test_serve_launcher_bf16_logits_match_jnp_decode():
+    """launch.serve at bf16: the Pallas decode path's first-token logits
+    agree with the jnp path on the same weights, and the KV cache is
+    tile-aligned."""
+    from repro.launch.serve import kv_len, make_params, serve
+    assert kv_len(128, 32) == 256 and kv_len(8, 4) == 128
+    cfg = get_config("olmo-1b").reduced()
+    params = make_params(cfg)
+    kw = dict(requests=2, prompt_len=8, max_new=3, slots=2)
+    pallas = serve(cfg, params, use_pallas=True, **kw)
+    plain = serve(cfg, params, use_pallas=False, **kw)
+    for a, b in zip(pallas, plain):
+        assert a.prompt_logits.shape == (cfg.vocab,)
+        np.testing.assert_allclose(a.prompt_logits, b.prompt_logits,
+                                   atol=0.05 * np.abs(b.prompt_logits).max())
+        assert len(a.generated) == 3
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    prior = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.CHECKOUT_CACHE.parent == REPO
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior)
+
+
 def test_kernel_opt_writes_registry(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_TUNED_KERNELS", str(tmp_path / "kernels.json"))
     import importlib
