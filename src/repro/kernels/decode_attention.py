@@ -105,5 +105,6 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
+        name="decode_attention",
     )(lengths, qf, kf, vf)
     return out.reshape(b, h, d)

@@ -57,4 +57,5 @@ def elementwise_chain(x: jnp.ndarray, epilogue: List[EpilogueOp], *,
         out_shape=jax.ShapeDtypeStruct((r, c), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
+        name="elementwise_chain",
     )(x, *[norm_ops[s] for s in op_names])

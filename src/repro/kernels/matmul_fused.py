@@ -165,6 +165,7 @@ def _matmul_epilogue_swizzled(a, b, norm_ops, op_names, epilogue, m, n, k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(dimension_semantics)[:2] or ("parallel", "arbitrary")),
         interpret=interpret,
+        name="matmul_fused_epilogue",
     )(a, b, *[norm_ops[s] for s in op_names])
 
 
@@ -235,6 +236,7 @@ def _matmul_reduce(a, b, norm_ops, op_names, epilogue, reduction, m, n, k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="matmul_fused_reduce",
     )(a, b, *[norm_ops[s] for s in op_names])
     return out[:, 0]
 
@@ -329,6 +331,7 @@ def matmul_fused_naive(a: jnp.ndarray, b: jnp.ndarray, *,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=resident + _VMEM_HEADROOM),
         interpret=resolve_interpret(interpret),
+        name="matmul_fused_naive",
     )(*args)
     if reduction is not None:
         # mean already rescaled in-kernel

@@ -42,4 +42,5 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
+        name="rmsnorm",
     )(x, w.reshape(1, d))
