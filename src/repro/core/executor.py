@@ -19,12 +19,25 @@ what ``run_group`` computes (a template choice, a clamped block, a dtype
 rule) must fold into the signature — and the batch planner's pre-executions
 (:meth:`OptimizationEngine._plan_batch`) dispatch through these same
 functions precisely so parent and worker derive bit-identical keys.
+
+Dispatch is one compiled executable per program structure. ``run_program``
+keys a bounded cache on everything it reads of a program (graph nodes and
+outputs, each group's nodes, impl and ``group_exec_signature``, the compute
+dtype and ``use_pallas``) and, on a miss, wraps its whole body in one
+``jax.jit`` over ``(inputs, params)``: a later call of the same structure is
+one launch, with no tracing or lowering of the Pallas kernels. So a new input
+that changes what ``run_group`` computes must enter both
+``group_exec_signature`` and that key (``_program_key``); one that enters
+neither would let two programs share an executable.
 """
 
 from __future__ import annotations
 
+import collections
+import threading
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.ir.graph import Graph, Node
@@ -282,25 +295,100 @@ def group_exec_signature(graph: Graph, group: FusionGroup,
             tuple(sorted(group.operand_layouts.items())), group.prefetch)
 
 
+class _ExecutableCache:
+    """Jitted programs by structural key, the least recently used dropped
+    first beyond ``cap``. Forge's threads share it, hence the lock."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self.hits = self.misses = 0
+        self._entries: "collections.OrderedDict[tuple, tuple]" = \
+            collections.OrderedDict()
+        self._lock = threading.Lock()
+
+    def get(self, key: tuple):
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                self.misses += 1
+            else:
+                self._entries.move_to_end(key)
+                self.hits += 1
+            return entry
+
+    def put(self, key: tuple, entry: tuple):
+        with self._lock:
+            self._entries[key] = entry
+            while len(self._entries) > self.cap:
+                self._entries.popitem(last=False)
+
+    def clear(self):
+        with self._lock:
+            self._entries.clear()
+
+    def stats(self) -> Dict[str, int]:
+        with self._lock:
+            return {"hits": self.hits, "misses": self.misses,
+                    "size": len(self._entries)}
+
+
+# Forge verification runs many one-shot programs through the same cache
+_executables = _ExecutableCache(cap=256)
+
+
+def _program_key(program: KernelProgram, use_pallas: bool) -> tuple:
+    """Everything :func:`run_program` reads of ``program``. Attributes enter
+    by ``repr``, which tells ``-0.0`` from ``0.0`` and ``1`` from ``1.0``."""
+    graph, sched = program.graph, program.schedule
+    return (tuple((n.name, n.op, tuple(n.inputs), repr(n.attrs),
+                   tuple(n.shape), n.dtype) for n in graph.nodes.values()),
+            tuple(graph.outputs),
+            tuple((g.name, tuple(g.nodes), g.impl,
+                   group_exec_signature(graph, g, use_pallas))
+                  for g in sched.groups),
+            sched.compute_dtype, use_pallas)
+
+
+def _compile_program(program: KernelProgram, use_pallas: bool):
+    """A jitted function of ``(inputs, params)`` returning the outputs in
+    ``graph.outputs`` order, traced from a copy of ``program`` so that a
+    later in-place edit of it cannot reach a retrace."""
+    graph, sched = program.graph.copy(), program.schedule.copy()
+    compute_dtype = jnp.dtype(sched.compute_dtype)
+
+    def program_body(inputs, params):
+        env: Dict[str, jnp.ndarray] = {}
+        for n in graph.toposorted():
+            if n.op == "input":
+                env[n.name] = inputs[n.name]
+            elif n.op == "param":
+                env[n.name] = params[n.name]
+            elif n.op == "const":
+                env[n.name] = jnp.asarray(n.attrs["value"], jnp.dtype(n.dtype))
+        for g in group_order(graph, sched.groups):
+            env.update(run_group(graph, g, env, compute_dtype,
+                                 use_pallas=use_pallas))
+        return tuple(env[o].astype(jnp.float32) for o in graph.outputs)
+
+    return jax.jit(program_body), tuple(graph.outputs)
+
+
+def program_cache_stats() -> Dict[str, int]:
+    """Hits, misses and size of :func:`run_program`'s executable cache."""
+    return _executables.stats()
+
+
 def run_program(program: KernelProgram,
                 inputs: Dict[str, jnp.ndarray],
                 params: Dict[str, jnp.ndarray],
                 use_pallas: bool = True) -> Dict[str, jnp.ndarray]:
-    graph = program.graph
-    sched = program.schedule
-    compute_dtype = jnp.dtype(sched.compute_dtype)
-    env: Dict[str, jnp.ndarray] = {}
-    for n in graph.toposorted():
-        if n.op == "input":
-            env[n.name] = inputs[n.name]
-        elif n.op == "param":
-            env[n.name] = params[n.name]
-        elif n.op == "const":
-            env[n.name] = jnp.asarray(n.attrs["value"], jnp.dtype(n.dtype))
-    for g in group_order(graph, sched.groups):
-        env.update(run_group(graph, g, env, compute_dtype,
-                             use_pallas=use_pallas))
-    return {o: env[o].astype(jnp.float32) for o in graph.outputs}
+    key = _program_key(program, use_pallas)
+    entry = _executables.get(key)
+    fn, outputs = entry or _compile_program(program, use_pallas)
+    out = fn(inputs, params)     # a trace that raises stores nothing
+    if entry is None:
+        _executables.put(key, (fn, outputs))
+    return dict(zip(outputs, out))
 
 
 def program_callable(program: KernelProgram, params: Dict[str, jnp.ndarray],

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import executor as ex
 from repro.core.executor import run_program
 from repro.ir import GraphBuilder
 from repro.ir.cost import graph_flops
@@ -58,6 +59,7 @@ def host_events(trace_dir, names) -> list:
 
 
 def test_pallas_lowering_is_a_named_span_inside_the_call(tmp_path):
+    ex._executables.clear()       # a first call, whatever ran before in this process
     p = two_group_program()
     inputs, params = make_inputs(p.graph), make_params(p.graph)
     assert [g.impl for g in p.schedule.groups] == ["pallas_blockspec", "xla"]
