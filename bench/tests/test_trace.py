@@ -79,15 +79,30 @@ def test_recorded_gemm_calls():
     assert 3 * 174e-6 < s.busy_s < 3 * 200e-6
 
 
-def test_recorded_decode_steps():
-    """Ten OLMo-1B decode steps (32 slots, KV 384, Pallas decode
-    attention) recorded on a TPU v5e."""
-    s = trace.summarize(trace.load_file(DATA / "v5e_decode_steps.xplane.pb"),
-                        window_s=0.2, chips=1)
+# OLMo-1B decode steps (KV 384, Pallas decode attention) recorded on a TPU
+# v5e: ten steps at 32 slots, from before the kernel had a name of its own;
+# and one wave of the batch serving cell at 64 slots (two prompt and three
+# answer tokens a request, so four steps), named ``decode_attention``.
+DECODE_TRACES = {
+    "v5e_decode_steps": dict(window_s=0.2, steps=10, name="%closed_call",
+                             kernel_s=(0.030, 0.050), busy_s=(0.15, 0.2)),
+    "v5e_serve_batch_wave": dict(window_s=0.18183831300000008, steps=4,
+                                 name="%decode_attention",
+                                 kernel_s=(0.025, 0.045),
+                                 busy_s=(0.14, 0.18)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_TRACES))
+def test_recorded_decode_steps(name):
+    t = DECODE_TRACES[name]
+    s = trace.summarize(trace.load_file(DATA / f"{name}.xplane.pb"),
+                        window_s=t["window_s"], chips=1)
     match = readers.attention_kernel(128)
     kernel = [n for n, *_ in s.ops if match(n)]
-    assert len(kernel) == 10 * 16          # one call per layer per step
-    assert 0.030 < s.op_seconds(match) < 0.050
+    assert len(kernel) == t["steps"] * 16     # one call per layer per step
+    assert all(n.startswith(t["name"]) for n in kernel)
+    assert t["kernel_s"][0] < s.op_seconds(match) < t["kernel_s"][1]
     assert not any(n.startswith("%while") for n, *_ in s.ops)
     assert s.top_ops(1)[0][0] == kernel[0].split(" = ")[0]
-    assert 0.15 < s.busy_s < 0.2
+    assert t["busy_s"][0] < s.busy_s < t["busy_s"][1]
