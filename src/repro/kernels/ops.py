@@ -24,6 +24,7 @@ from repro.kernels.epilogue import EpilogueOp
 from repro.kernels.matmul_fused import matmul_fused
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.decode_attention import decode_attention
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.elementwise import elementwise_chain
 from repro.kernels.platform import resolve_interpret
@@ -119,6 +120,19 @@ def decode_attn(q, k, v, *, lengths=None, use_pallas: bool = True,
     cfg = config or REGISTRY.get("decode_attention", _sig(s, q.shape[-1], q.dtype)) or {}
     return decode_attention(q, k, v, lengths=lengths,
                             block_kv=cfg.get("block_kv", min(512, s)))
+
+
+def experts(x, counts, wi, wg, wo, *, use_pallas: bool = True,
+            config: Optional[Dict] = None):
+    """Grouped SwiGLU over the held experts; x: [E, C, D] grouped by expert,
+    counts: [E] rows each."""
+    if not use_pallas:
+        return ref_ops.moe_experts_ref(x, counts, wi, wg, wo)
+    cfg = config or REGISTRY.get(
+        "moe_experts", _sig(x.shape[1], x.shape[2], wi.shape[-1], x.dtype)) or {}
+    return moe_experts(x, counts, wi, wg, wo,
+                       block_f=cfg.get("block_f", 256),
+                       block_rows=cfg.get("block_rows", 64))
 
 
 def rms_norm(x, w, *, eps=1e-6, use_pallas: bool = True):

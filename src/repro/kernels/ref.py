@@ -78,6 +78,19 @@ def decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 # ----------------------------------------------------------------------
+def moe_experts_ref(x: jnp.ndarray, counts: jnp.ndarray, wi: jnp.ndarray,
+                    wg: jnp.ndarray, wo: jnp.ndarray) -> jnp.ndarray:
+    """Grouped SwiGLU oracle. x: [E, C, D] grouped by expert, counts: [E];
+    rows at or past an expert's count give zero."""
+    h = jnp.einsum("ecd,edf->ecf", x, wi, preferred_element_type=jnp.float32)
+    g = jnp.einsum("ecd,edf->ecf", x, wg, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(h) * g).astype(x.dtype)
+    y = jnp.einsum("ecf,efd->ecd", a, wo, preferred_element_type=jnp.float32)
+    live = jnp.arange(x.shape[1])[None, :, None] < counts[:, None, None]
+    return jnp.where(live, y, 0.0).astype(x.dtype)
+
+
+# ----------------------------------------------------------------------
 def rmsnorm_ref(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)

@@ -36,45 +36,54 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     chunk = min(chunk, l)
     assert l % chunk == 0, f"seq len {l} must be divisible by chunk {chunk}"
     nchunks = l // chunk
+    # the step sizes ride in as a row [1, Q] and as a column [Q, 1] of each
+    # chunk (Mosaic tiles the last two dims of a block), the decay rates in
+    # SMEM as scalar prefetch; prefix sums are products with a triangle
+    dt32 = dt.astype(jnp.float32)
+    dt_row, dt_col = dt32.reshape(bh, 1, l), dt32.reshape(bh, l, 1)
+    a_s = a.reshape(bh).astype(jnp.float32)
 
-    def kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref, state_ref):
-        ci = pl.program_id(1)
+    def kernel(a_ref, x_ref, dtr_ref, dtc_ref, b_ref, c_ref, y_ref, s_ref,
+               state_ref):
+        i, ci = pl.program_id(0), pl.program_id(1)
 
         @pl.when(ci == 0)
         def _():
             state_ref[...] = jnp.zeros_like(state_ref)
 
         xq = x_ref[0].astype(jnp.float32)          # [Q, P]
-        dtq = dt_ref[0].astype(jnp.float32)        # [Q]
-        av = a_ref[0, 0].astype(jnp.float32)       # scalar
+        av = a_ref[i]                              # scalar, < 0
+        dtr, dtc = dtr_ref[0], dtc_ref[0]          # [1, Q], [Q, 1]
         bq = b_ref[0].astype(jnp.float32)          # [Q, N]
         cq = c_ref[0].astype(jnp.float32)          # [Q, N]
 
-        aq = dtq * av                              # [Q], <= 0
-        cums = jnp.cumsum(aq)                      # [Q]
+        ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        lower = (ii >= jj).astype(jnp.float32)
+        cums_c = jax.lax.dot_general(lower, dtc * av, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        cums_r = jax.lax.dot_general(dtr * av, lower, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        total = jnp.sum(dtr * av, axis=1, keepdims=True)         # [1, 1]
 
         # inter-chunk: contribution of the carried state
         y_inter = jax.lax.dot_general(
             cq, state_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.exp(cums)[:, None]  # [Q, P]
+            preferred_element_type=jnp.float32) * jnp.exp(cums_c)  # [Q, P]
 
         # intra-chunk: masked decay-weighted "attention"
         scores = jax.lax.dot_general(cq, bq, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)  # [Q, Q]
-        li = cums[:, None] - cums[None, :]
-        ii = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-        jj = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        decay = jnp.where(ii >= jj, jnp.exp(li), 0.0)
-        w = scores * decay * dtq[None, :]
+        decay = jnp.where(ii >= jj, jnp.exp(cums_c - cums_r), 0.0)
+        w = scores * decay * dtr
         y_intra = jax.lax.dot_general(w, xq, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
 
         y_ref[0] = (y_inter + y_intra).astype(y_ref.dtype)
 
         # state update: S' = exp(cums_Q) S + sum_j exp(cums_Q - cums_j) dt_j x_j b_j^T
-        total = cums[-1]
-        wgt = jnp.exp(total - cums) * dtq          # [Q]
-        ds = jax.lax.dot_general(xq * wgt[:, None], bq, (((0,), (0,)), ((), ())),
+        wgt = jnp.exp(total - cums_c) * dtc        # [Q, 1]
+        ds = jax.lax.dot_general(xq * wgt, bq, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [P, N]
         state_ref[...] = state_ref[...] * jnp.exp(total) + ds
 
@@ -84,25 +93,27 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
     y, s = pl.pallas_call(
         kernel,
-        grid=(bh, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, ci: (i, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda i, ci: (i, ci)),
-            pl.BlockSpec((1, 1), lambda i, ci: (i, 0)),
-            pl.BlockSpec((1, chunk, n), lambda i, ci: (i, ci, 0)),
-            pl.BlockSpec((1, chunk, n), lambda i, ci: (i, ci, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, ci: (i, ci, 0)),
-            pl.BlockSpec((1, p, n), lambda i, ci: (i, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nchunks),
+            in_specs=[
+                pl.BlockSpec((1, chunk, p), lambda i, ci, a_: (i, ci, 0)),
+                pl.BlockSpec((1, 1, chunk), lambda i, ci, a_: (i, 0, ci)),
+                pl.BlockSpec((1, chunk, 1), lambda i, ci, a_: (i, ci, 0)),
+                pl.BlockSpec((1, chunk, n), lambda i, ci, a_: (i, ci, 0)),
+                pl.BlockSpec((1, chunk, n), lambda i, ci, a_: (i, ci, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, p), lambda i, ci, a_: (i, ci, 0)),
+                pl.BlockSpec((1, p, n), lambda i, ci, a_: (i, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, l, p), x.dtype),
             jax.ShapeDtypeStruct((bh, p, n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(x, dt, a, b, c)
+    )(a_s, x, dt_row, dt_col, b, c)
     return y, s

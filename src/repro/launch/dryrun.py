@@ -44,13 +44,13 @@ def count_params(abstract_p) -> float:
 
 
 def active_params(cfg: ModelConfig, abstract_p) -> float:
-    """N_active: MoE experts count at top_k/E weight."""
+    """N_active: MoE experts (all of them, or the share held) count at
+    top_k/E weight."""
     total = 0.0
     for path, leaf in jax.tree_util.tree_flatten_with_path(abstract_p)[0]:
-        keys = [getattr(p, "key", getattr(p, "idx", "")) for p in path]
-        is_expert = cfg.moe is not None and any(
-            str(k) in ("wi", "wg", "wo") for k in keys) and leaf.ndim >= 3 \
-            and cfg.moe.num_experts in leaf.shape
+        keys = [str(getattr(p, "key", getattr(p, "idx", ""))) for p in path]
+        is_expert = cfg.moe is not None and "moe" in keys \
+            and keys[-1] in ("wi", "wg", "wo")
         if is_expert:
             total += leaf.size * cfg.moe.top_k / cfg.moe.num_experts
         else:

@@ -79,16 +79,17 @@ def init_norm(cfg: ModelConfig, dtype) -> Dict:
 def apply_norm(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
                use_pallas: bool = False) -> jnp.ndarray:
     x32 = x.astype(jnp.float32)
+    eps = cfg.resolved_norm_eps
     if cfg.norm == "layernorm":
         mu = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.var(x32, axis=-1, keepdims=True)
-        y = (x32 - mu) * jax.lax.rsqrt(var + 1e-5)
+        y = (x32 - mu) * jax.lax.rsqrt(var + eps)
     else:
         if use_pallas and x.ndim >= 2 and not cfg.non_parametric_ln:
             from repro.kernels.ops import rms_norm
-            return rms_norm(x, p["scale"])
+            return rms_norm(x, p["scale"], eps=eps)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = x32 * jax.lax.rsqrt(var + 1e-6)
+        y = x32 * jax.lax.rsqrt(var + eps)
     if not cfg.non_parametric_ln and "scale" in p:
         y = y * p["scale"].astype(jnp.float32)
     return y.astype(x.dtype)
@@ -135,9 +136,9 @@ def init_attention(cfg: ModelConfig, key, dtype, cross: bool = False) -> Dict:
     return p
 
 
-def _qk_normalize(q, scale):
+def _qk_normalize(q, scale, eps):
     var = jnp.mean(jnp.square(q.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (q.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    return (q.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
             * scale.astype(jnp.float32)).astype(q.dtype)
 
 
@@ -156,8 +157,8 @@ def _project_qkv(cfg: ModelConfig, p: Dict, x, kv_x, positions, kv_positions,
     k = k.reshape(b, skv, hkv, dh)
     v = v.reshape(b, skv, hkv, dh)
     if cfg.qk_norm:
-        q = _qk_normalize(q, p["q_norm"])
-        k = _qk_normalize(k, p["k_norm"])
+        q = _qk_normalize(q, p["q_norm"], cfg.resolved_norm_eps)
+        k = _qk_normalize(k, p["k_norm"], cfg.resolved_norm_eps)
     if use_rope:
         q = rope(q.swapaxes(1, 2), positions, cfg.rope_theta).swapaxes(1, 2)
         k = rope(k.swapaxes(1, 2), kv_positions, cfg.rope_theta).swapaxes(1, 2)
@@ -354,8 +355,8 @@ def decode_attention_step(cfg: ModelConfig, p: Dict, x, cache_k, cache_v,
 # MLP / MoE
 # ======================================================================
 
-def init_mlp(cfg: ModelConfig, key, dtype) -> Dict:
-    d, f = cfg.d_model, cfg.d_ff
+def init_mlp(cfg: ModelConfig, key, dtype, d_ff: Optional[int] = None) -> Dict:
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     ks = jax.random.split(key, 3)
     p = {"wi": _dense_init(ks[0], (d, f), dtype),
          "wo": _dense_init(ks[1], (f, d), dtype)}
@@ -376,14 +377,31 @@ def apply_mlp(cfg: ModelConfig, p: Dict, x, use_pallas: bool = False):
 
 
 def init_moe(cfg: ModelConfig, key, dtype) -> Dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    """The router over every expert, and the weights of the experts held
+    (all of them unless ``cfg.moe.held`` says otherwise)."""
+    m = cfg.moe
+    d, f, e = cfg.d_model, cfg.d_ff, m.num_experts
+    held = e if m.held is None else m.held
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "router": _dense_init(ks[0], (d, e), dtype),
-        "wi": _dense_init(ks[1], (e, d, f), dtype),
-        "wg": _dense_init(ks[2], (e, d, f), dtype),
-        "wo": _dense_init(ks[3], (e, f, d), dtype),
+        "wi": _dense_init(ks[1], (held, d, f), dtype),
+        "wg": _dense_init(ks[2], (held, d, f), dtype),
+        "wo": _dense_init(ks[3], (held, f, d), dtype),
     }
+    if m.router == "sigmoid":
+        p["expert_bias"] = jnp.zeros((e,), jnp.float32)
+    return p
+
+
+def sigmoid_gates(cfg: ModelConfig, p: Dict, xt: jnp.ndarray):
+    """Sigmoid routing of tokens ``xt`` [T, D] over every expert: the top k
+    of score + expert bias [T, K], and their scores normalised to sum 1
+    (float32 gates [T, K])."""
+    scores = jax.nn.sigmoid((xt @ p["router"]).astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + p["expert_bias"], cfg.moe.top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
 
 
 def moe_route(cfg: ModelConfig, p: Dict, xt: jnp.ndarray,
@@ -411,7 +429,10 @@ def apply_moe(cfg: ModelConfig, p: Dict, x, use_pallas: bool = False,
     cumsum/scatter/gather machinery is vmapped over a dim that is sharded
     over DP — no cross-shard prefix sums, no replicated dispatch buffers
     under SPMD. Over-capacity tokens drop (standard). The EP all-to-all
-    variant lives in sharding/expert_parallel.py."""
+    variant lives in sharding/expert_parallel.py. A config that holds a
+    share of the experts (``cfg.moe.held``) takes :func:`apply_moe_held`."""
+    if cfg.moe.held is not None:
+        return apply_moe_held(cfg, p, x, use_pallas)[0]
     b, s, d = x.shape
     e, top_k = cfg.moe.num_experts, cfg.moe.top_k
     tg = s                                        # tokens per group
@@ -456,12 +477,54 @@ def apply_moe(cfg: ModelConfig, p: Dict, x, use_pallas: bool = False,
     return y.reshape(b, s, d)
 
 
+def apply_moe_held(cfg: ModelConfig, p: Dict, x, use_pallas: bool = False):
+    """The part of an expert layer that the held experts give: every token
+    is routed over all ``num_experts``, and each token-expert pair whose
+    expert is held here is computed, none dropped (a token picks an expert
+    at most once, so a group of T rows holds any routing). Pairs routed
+    elsewhere add nothing here; under expert parallelism the shares of the
+    other chips add up to the rest. x: [..., D]. Returns the part, and the
+    rows each held expert computed [held] int32."""
+    from repro.kernels.ops import experts
+    m = cfg.moe
+    d = x.shape[-1]
+    xt = x.reshape(-1, d)
+    t, k = xt.shape[0], m.top_k
+    idx, gates = sigmoid_gates(cfg, p, xt)
+    local = idx.reshape(-1) - m.first_held                 # [T*K]
+    mine = (local >= 0) & (local < m.held)
+    group = jnp.where(mine, local, m.held)                 # m.held: not here
+    order = jnp.argsort(group, stable=True)                # pairs by group
+    counts = jnp.sum(group[:, None] == jnp.arange(m.held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    # row r of expert e's group holds the token of its (starts[e] + r)-th
+    # pair in sorted order; rows at or past counts[e] are unused
+    pick = jnp.minimum(starts[:, None] + jnp.arange(t)[None, :], t * k - 1)
+    out = experts(xt[order[pick] // k], counts, p["wi"], p["wg"], p["wo"],
+                  use_pallas=use_pallas)                   # [held, T, D]
+    rank = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    row = rank - starts[jnp.minimum(group, m.held - 1)]
+    rows = out[jnp.where(mine, local, 0), jnp.where(mine, row, 0)]
+    w = jnp.where(mine, gates.reshape(-1), 0.0).reshape(t, k)
+    y = jnp.sum(w[..., None] * rows.reshape(t, k, d).astype(jnp.float32),
+                axis=1)
+    return y.astype(x.dtype).reshape(x.shape), counts
+
+
 def moe_aux_loss(cfg: ModelConfig, p: Dict, x) -> jnp.ndarray:
-    """Switch-style load-balancing auxiliary loss."""
+    """Switch-style load-balancing auxiliary loss. Sigmoid routers take
+    their scores normalised over the experts as the probabilities."""
     b, s, d = x.shape
     e = cfg.moe.num_experts
     logits = (x.reshape(-1, d) @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.moe.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        logits = scores + p["expert_bias"]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(logits, cfg.moe.top_k)
     frac = jnp.mean(jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32), axis=0)
     return e * jnp.sum(frac * jnp.mean(probs, axis=0))

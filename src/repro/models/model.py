@@ -7,9 +7,11 @@
   decode_step(cfg, params, cache, tokens, pos, flags) -> (logits, cache)
 
 Families: dense / moe (scan-over-layers), ssm (mamba2, scan), hybrid
-(recurrentgemma, per-layer loop over the block pattern), encdec (whisper,
-scan per stack). ``batch`` may carry ``prefix_embeds`` (VLM patch stub) or
-``frames`` (audio frame stub) per the assignment's frontend-stub rule.
+(recurrentgemma, lfm2: per-layer loop over the block pattern of attention,
+RG-LRU and short-conv mixers, each with a dense MLP or held experts), encdec
+(whisper, scan per stack). ``batch`` may carry ``prefix_embeds`` (VLM patch
+stub) or ``frames`` (audio frame stub) per the assignment's frontend-stub
+rule.
 """
 
 from __future__ import annotations
@@ -66,21 +68,63 @@ def _init_encdec_layer(cfg: ModelConfig, key, dtype, decoder: bool) -> Dict:
     return p
 
 
-def _init_hybrid_layer(cfg: ModelConfig, key, dtype, kind: str) -> Dict:
+def _init_hybrid_layer(cfg: ModelConfig, key, dtype, kind: str,
+                       index: int) -> Dict:
     ks = jax.random.split(key, 3)
-    p = {"ln1": L.init_norm(cfg, dtype), "ln2": L.init_norm(cfg, dtype),
-         "kind": kind,
-         "mlp": L.init_mlp(cfg, ks[1], dtype)}
+    p = {"ln1": L.init_norm(cfg, dtype), "ln2": L.init_norm(cfg, dtype)}
+    if cfg.moe is not None and index >= cfg.num_dense_layers:
+        p["moe"] = L.init_moe(cfg, ks[1], dtype)
+    else:
+        p["mlp"] = L.init_mlp(cfg, ks[1], dtype, d_ff=cfg.dense_d_ff)
     if kind == "attn":
         p["attn"] = L.init_attention(cfg, ks[0], dtype)
+    elif kind == "conv":
+        p["conv"] = R.init_short_conv(cfg, ks[0], dtype)
     else:
         p["rglru"] = R.init_rglru_block(cfg, ks[0], dtype)
     return p
 
 
+def _holds_experts(cfg: ModelConfig, i: int) -> bool:
+    """Layer i of a hybrid computes a held share of its experts."""
+    return (cfg.moe is not None and cfg.moe.held is not None
+            and i >= cfg.num_dense_layers)
+
+
 def hybrid_pattern(cfg: ModelConfig):
     pat = cfg.block_pattern or ("attn",)
     return [pat[i % len(pat)] for i in range(cfg.num_layers)]
+
+
+def _hybrid_ffn(cfg: ModelConfig, flags: RuntimeFlags, layer, h):
+    """The hybrid layer's feed-forward half, with its residual: (h, aux,
+    counts), counts being the rows each held expert computed (None unless
+    the layer holds a share of its experts)."""
+    inner = L.apply_norm(cfg, layer["ln2"], h, flags.use_pallas)
+    if "moe" not in layer:
+        return (h + L.apply_mlp(cfg, layer["mlp"], inner, flags.use_pallas),
+                jnp.zeros((), jnp.float32), None)
+    aux = L.moe_aux_loss(cfg, layer["moe"], inner)
+    if cfg.moe.held is not None:
+        y, counts = L.apply_moe_held(cfg, layer["moe"], inner,
+                                     flags.use_pallas)
+        return h + y, aux, counts
+    return h + L.apply_moe(cfg, layer["moe"], inner, flags.use_pallas,
+                           capacity_factor=flags.moe_capacity_factor), aux, None
+
+
+def _expert_counters(cfg: ModelConfig) -> Dict:
+    """A held-expert layer's cache entry counts its experts' work since the
+    cache was made: the rows each computed (``expert_rows``) and the calls
+    in which it had any (``expert_calls``), so that a caller can tell the
+    weights those calls had to read."""
+    z = jnp.zeros((cfg.moe.held,), jnp.int32)
+    return {"expert_rows": z, "expert_calls": z}
+
+
+def _count_experts(entry: Dict, counts) -> Dict:
+    return {"expert_rows": entry["expert_rows"] + counts,
+            "expert_calls": entry["expert_calls"] + (counts > 0)}
 
 
 def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Dict:
@@ -106,9 +150,7 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Dict:
         lkeys = jax.random.split(keys[2], cfg.num_layers)
         kinds = hybrid_pattern(cfg)
         params["layers"] = [
-            {k: v for k, v in _init_hybrid_layer(cfg, lkeys[i], dtype,
-                                                 kinds[i]).items()
-             if k != "kind"}
+            _init_hybrid_layer(cfg, lkeys[i], dtype, kinds[i], i)
             for i in range(cfg.num_layers)]
     elif cfg.family == "encdec":
         ekeys = jax.random.split(keys[3], cfg.encoder_layers)
@@ -235,15 +277,13 @@ def forward_hidden(cfg: ModelConfig, params, batch: Dict,
                                         window=cfg.window,
                                         use_pallas=flags.use_pallas,
                                         chunked=flags.chunked_attention)
+            elif kind == "conv":
+                mix = R.apply_short_conv(cfg, layer["conv"], inner)
             else:
                 mix = R.apply_rglru_block(cfg, layer["rglru"], inner,
                                           flags.use_pallas)
-            h = h + mix
-            h = h + L.apply_mlp(cfg, layer["mlp"],
-                                L.apply_norm(cfg, layer["ln2"], h,
-                                             flags.use_pallas),
-                                flags.use_pallas)
-            return L.shard_hint(h, ("pod", "data"), "model", None)
+            h, a, _ = _hybrid_ffn(cfg, flags, layer, h + mix)
+            return L.shard_hint(h, ("pod", "data"), "model", None), a
 
         if flags.remat:
             # NOTE: prevent_cse must stay True here — the hybrid stack is an
@@ -252,7 +292,8 @@ def forward_hidden(cfg: ModelConfig, params, batch: Dict,
             # (prevent_cse=False is only safe inside scan bodies).
             hybrid_layer = jax.checkpoint(hybrid_layer, static_argnums=(2,))
         for i, layer in enumerate(params["layers"]):
-            x = hybrid_layer(x, layer, kinds[i])
+            x, a = hybrid_layer(x, layer, kinds[i])
+            aux = aux + a
     else:
         raise ValueError(cfg.family)
 
@@ -387,8 +428,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                 cache[f"layer_{i}"] = {
                     "k": jnp.zeros((batch, w, cfg.kv_heads, dh), dtype),
                     "v": jnp.zeros((batch, w, cfg.kv_heads, dh), dtype)}
+            elif kind == "conv":
+                cache[f"layer_{i}"] = R.short_conv_state_init(cfg, batch)
             else:
                 cache[f"layer_{i}"] = R.rglru_state_init(cfg, batch)
+            if _holds_experts(cfg, i):
+                cache[f"layer_{i}"].update(_expert_counters(cfg))
         return cache
     if cfg.family == "encdec":
         shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, dh)
@@ -454,14 +499,16 @@ def decode_step(cfg: ModelConfig, params, cache: Dict, tokens: jnp.ndarray,
                     cfg, layer["attn"], inner, entry["k"], entry["v"],
                     position, window=cfg.window, use_pallas=flags.use_pallas)
                 cache[f"layer_{i}"] = {"k": ck, "v": cv}
+            elif kinds[i] == "conv":
+                out, st = R.short_conv_step(cfg, layer["conv"], inner, entry)
+                cache[f"layer_{i}"] = st
             else:
                 out, st = R.rglru_block_step(cfg, layer["rglru"], inner, entry)
                 cache[f"layer_{i}"] = st
-            x = x + out
-            x = x + L.apply_mlp(cfg, layer["mlp"],
-                                L.apply_norm(cfg, layer["ln2"], x,
-                                             flags.use_pallas),
-                                flags.use_pallas)
+            x, _, counts = _hybrid_ffn(cfg, flags, layer, x + out)
+            if counts is not None:
+                cache[f"layer_{i}"] = dict(cache[f"layer_{i}"],
+                                           **_count_experts(entry, counts))
     else:
         raise ValueError(cfg.family)
 
@@ -487,7 +534,9 @@ def _cross_decode(cfg, layer, x, xk, xv):
 
 def prefill(cfg: ModelConfig, params, batch: Dict,
             flags: RuntimeFlags = DEFAULT_FLAGS) -> Tuple[jnp.ndarray, Dict]:
-    """Full-context forward that also returns the serving cache."""
+    """Full-context forward that also returns the serving cache. A hybrid
+    model's full-attention layers get a cache of ``cfg.max_seq_len``
+    positions, room for the decode steps that follow."""
     if cfg.family in ("ssm", "hybrid"):
         return _prefill_recurrent(cfg, params, batch, flags)
     if cfg.family == "encdec":
@@ -524,7 +573,7 @@ def prefill(cfg: ModelConfig, params, batch: Dict,
 
 def _prefill_recurrent(cfg, params, batch, flags):
     """SSM / hybrid prefill: run the sequence, keep final states (and the
-    rolling attention window for hybrid)."""
+    rolling attention window, or the padded KV cache, for hybrid)."""
     x = _embed(cfg, params, batch)
     bsz, s, _ = x.shape
     if cfg.family == "ssm":
@@ -543,7 +592,6 @@ def _prefill_recurrent(cfg, params, batch, flags):
     # hybrid
     kinds = hybrid_pattern(cfg)
     cache = {}
-    w = min(cfg.window or s, s)
     for i, layer in enumerate(params["layers"]):
         inner = L.apply_norm(cfg, layer["ln1"], x, flags.use_pallas)
         if kinds[i] == "attn":
@@ -553,22 +601,32 @@ def _prefill_recurrent(cfg, params, batch, flags):
             out = L.grouped_attention(q, k, v, causal=True, window=cfg.window,
                                       chunked=flags.chunked_attention)
             x = x + out.reshape(*out.shape[:2], -1) @ layer["attn"]["wo"]
-            cache[f"layer_{i}"] = {"k": k[:, -w:], "v": v[:, -w:]}
+            if cfg.window:
+                w = min(cfg.window, s)
+                cache[f"layer_{i}"] = {"k": k[:, -w:], "v": v[:, -w:]}
+            else:
+                room = ((0, 0), (0, cfg.max_seq_len - s), (0, 0), (0, 0))
+                cache[f"layer_{i}"] = {"k": jnp.pad(k, room),
+                                       "v": jnp.pad(v, room)}
+        elif kinds[i] == "conv":
+            out, cache[f"layer_{i}"] = R.apply_short_conv(
+                cfg, layer["conv"], inner, return_state=True)
+            x = x + out
         else:
             gate = jax.nn.gelu(inner @ layer["rglru"]["wgate"])
             u_raw = inner @ layer["rglru"]["wx"]
             u = R._causal_conv(u_raw, layer["rglru"]["conv_w"],
-                               layer["rglru"]["conv_b"])
+                               layer["rglru"]["conv_b"], cfg.conv_width)
             a, gin = R._rglru_gates(layer["rglru"], u)
             hseq = R.rglru_scan(a, gin)
             x = x + (hseq.astype(x.dtype) * gate) @ layer["rglru"]["wo"]
             cache[f"layer_{i}"] = {
-                "conv": u_raw[:, -(R.CONV_K - 1):].astype(jnp.float32),
+                "conv": u_raw[:, -(cfg.conv_width - 1):].astype(jnp.float32),
                 "h": hseq[:, -1]}
-        x = x + L.apply_mlp(cfg, layer["mlp"],
-                            L.apply_norm(cfg, layer["ln2"], x,
-                                         flags.use_pallas),
-                            flags.use_pallas)
+        x, _, counts = _hybrid_ffn(cfg, flags, layer, x)
+        if counts is not None:
+            cache[f"layer_{i}"].update(
+                _count_experts(_expert_counters(cfg), counts))
     x = L.apply_norm(cfg, params["ln_f"], x[:, -1:], flags.use_pallas)
     return _unembed(cfg, params, x)[:, 0], cache
 

@@ -1,4 +1,5 @@
-"""Recurrent blocks: Mamba-2 (SSD) and RG-LRU (Griffin/RecurrentGemma).
+"""Recurrent blocks: Mamba-2 (SSD), RG-LRU (Griffin/RecurrentGemma) and the
+gated short convolution (LFM2).
 
 Both expose a full-sequence ``apply_*`` (training/prefill) and a single-token
 ``*_step`` (decode) driven by explicit state pytrees — O(1) decode memory,
@@ -14,8 +15,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import _dense_init
-
-CONV_K = 4  # short causal conv width (mamba2 / griffin convention)
 
 
 # ======================================================================
@@ -42,7 +41,8 @@ def init_mamba_block(cfg: ModelConfig, key, dtype) -> Dict:
         "wdt": _dense_init(ks[4], (d, h), dtype),
         "dt_bias": jnp.zeros((h,), dtype),
         "a_log": jnp.zeros((h,), jnp.float32),            # a = -exp(a_log)
-        "conv_w": _dense_init(ks[5], (CONV_K, conv_dim), dtype, scale=0.5),
+        "conv_w": _dense_init(ks[5], (cfg.conv_width, conv_dim), dtype,
+                              scale=0.5),
         "conv_b": jnp.zeros((conv_dim,), dtype),
         "d_skip": jnp.ones((h,), jnp.float32),
         "norm_scale": jnp.ones((d_inner,), dtype),
@@ -50,11 +50,12 @@ def init_mamba_block(cfg: ModelConfig, key, dtype) -> Dict:
     }
 
 
-def _causal_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal conv, width CONV_K. u: [B, S, C]; w: [K, C]."""
-    pad = jnp.pad(u, ((0, 0), (CONV_K - 1, 0), (0, 0)))
-    out = sum(pad[:, i:i + u.shape[1]] * w[i] for i in range(CONV_K))
-    return out + b
+def _causal_conv(u: jnp.ndarray, w: jnp.ndarray, b, width: int) -> jnp.ndarray:
+    """Depthwise causal conv of ``width`` taps. u: [B, S, C]; w: [width, C];
+    b: [C] or None."""
+    pad = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
+    out = sum(pad[:, i:i + u.shape[1]] * w[i] for i in range(width))
+    return out if b is None else out + b
 
 
 def _gated_rmsnorm(y: jnp.ndarray, z: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -73,7 +74,8 @@ def apply_mamba_block(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     z = x @ p["wz"]
     bc = jnp.concatenate([x @ p["wb"], x @ p["wc"]], axis=-1)
     u_raw = jnp.concatenate([xp, bc], axis=-1)
-    u = jax.nn.silu(_causal_conv(u_raw, p["conv_w"], p["conv_b"]))
+    u = jax.nn.silu(_causal_conv(u_raw, p["conv_w"], p["conv_b"],
+                                 cfg.conv_width))
     xp, b_in, c_in = jnp.split(u, [d_inner, d_inner + n], axis=-1)
     dt = jax.nn.softplus((x @ p["wdt"]).astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))   # [B, S, H]
@@ -87,7 +89,7 @@ def apply_mamba_block(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     y = _gated_rmsnorm(y, z, p["norm_scale"])
     out = y @ p["wo"]
     if return_state:
-        state = {"conv": u_raw[:, -(CONV_K - 1):].astype(jnp.float32),
+        state = {"conv": u_raw[:, -(cfg.conv_width - 1):].astype(jnp.float32),
                  "ssm": final_state}
         return out, state
     return out
@@ -97,7 +99,7 @@ def mamba_state_init(cfg: ModelConfig, batch: int, dtype=jnp.float32) -> Dict:
     d_inner, h, n, hd = mamba_dims(cfg)
     conv_dim = d_inner + 2 * n
     return {
-        "conv": jnp.zeros((batch, CONV_K - 1, conv_dim), dtype),
+        "conv": jnp.zeros((batch, cfg.conv_width - 1, conv_dim), dtype),
         "ssm": jnp.zeros((batch, h, hd, n), jnp.float32),
     }
 
@@ -145,7 +147,8 @@ def init_rglru_block(cfg: ModelConfig, key, dtype) -> Dict:
     return {
         "wx": _dense_init(ks[0], (d, d_rnn), dtype),
         "wgate": _dense_init(ks[1], (d, d_rnn), dtype),
-        "conv_w": _dense_init(ks[2], (CONV_K, d_rnn), dtype, scale=0.5),
+        "conv_w": _dense_init(ks[2], (cfg.conv_width, d_rnn), dtype,
+                              scale=0.5),
         "conv_b": jnp.zeros((d_rnn,), dtype),
         "w_r": _dense_init(ks[3], (d_rnn, d_rnn), dtype),
         "b_r": jnp.zeros((d_rnn,), jnp.float32),
@@ -212,7 +215,7 @@ def apply_rglru_block(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
                       use_pallas: bool = False) -> jnp.ndarray:
     """Full-sequence forward via chunked linear recurrence. x: [B, S, D]."""
     gate = jax.nn.gelu(x @ p["wgate"])
-    u = _causal_conv(x @ p["wx"], p["conv_w"], p["conv_b"])
+    u = _causal_conv(x @ p["wx"], p["conv_w"], p["conv_b"], cfg.conv_width)
     a, gin = _rglru_gates(p, u)                               # [B, S, d_rnn]
     h = rglru_scan(a, gin)
     y = (h.astype(x.dtype) * gate)
@@ -222,7 +225,7 @@ def apply_rglru_block(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
 def rglru_state_init(cfg: ModelConfig, batch: int) -> Dict:
     d_rnn = cfg.d_model
     return {
-        "conv": jnp.zeros((batch, CONV_K - 1, d_rnn), jnp.float32),
+        "conv": jnp.zeros((batch, cfg.conv_width - 1, d_rnn), jnp.float32),
         "h": jnp.zeros((batch, d_rnn), jnp.float32),
     }
 
@@ -240,3 +243,55 @@ def rglru_block_step(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     h = a * state["h"] + gin
     y = (h.astype(x.dtype) * gate) @ p["wo"]
     return y[:, None], {"conv": window[:, 1:], "h": h}
+
+
+# ======================================================================
+# Gated short convolution (LFM2)
+# ======================================================================
+
+def init_short_conv(cfg: ModelConfig, key, dtype) -> Dict:
+    d = cfg.d_model
+    ks = jax.random.split(key, 3)
+    return {
+        "w_in": _dense_init(ks[0], (d, 3 * d), dtype),     # -> B, C, x
+        "conv_w": _dense_init(ks[1], (cfg.conv_width, d), dtype, scale=0.5),
+        "w_out": _dense_init(ks[2], (d, d), dtype),
+    }
+
+
+def _short_conv_in(p: Dict, x: jnp.ndarray):
+    """(C, B*x in float32): the output gate, and what the conv runs over."""
+    b, c, xs = jnp.split(x @ p["w_in"], 3, axis=-1)
+    return c, (b * xs).astype(jnp.float32)
+
+
+def apply_short_conv(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
+                     return_state: bool = False):
+    """Full-sequence forward: out = W_out(C * conv(B * x)), the conv causal
+    and depthwise over time, in float32. x: [B, S, D]. With
+    ``return_state`` also the decode state: the conv's last ``width - 1``
+    inputs, zeros before the sequence's start."""
+    c, v = _short_conv_in(p, x)
+    conv = _causal_conv(v, p["conv_w"].astype(jnp.float32), None,
+                        cfg.conv_width)
+    out = (c.astype(jnp.float32) * conv).astype(x.dtype) @ p["w_out"]
+    if return_state:
+        tail = jnp.pad(v, ((0, 0), (cfg.conv_width - 1, 0), (0, 0)))
+        return out, {"conv": tail[:, v.shape[1]:]}
+    return out
+
+
+def short_conv_state_init(cfg: ModelConfig, batch: int) -> Dict:
+    return {"conv": jnp.zeros((batch, cfg.conv_width - 1, cfg.d_model),
+                              jnp.float32)}
+
+
+def short_conv_step(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
+                    state: Dict) -> Tuple[jnp.ndarray, Dict]:
+    """One-token decode. x: [B, 1, D]; state["conv"]: [B, width - 1, D]."""
+    c, v = _short_conv_in(p, x[:, 0])
+    window = jnp.concatenate([state["conv"], v[:, None]], axis=1)
+    w = p["conv_w"].astype(jnp.float32)
+    conv = sum(window[:, i] * w[i] for i in range(cfg.conv_width))
+    out = (c.astype(jnp.float32) * conv).astype(x.dtype) @ p["w_out"]
+    return out[:, None], {"conv": window[:, 1:]}

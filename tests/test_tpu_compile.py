@@ -17,9 +17,12 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.elementwise import elementwise_chain
 from repro.kernels.epilogue import EpilogueOp
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (attention_unoptimized,
+                                           flash_attention)
 from repro.kernels.matmul_fused import matmul_fused, matmul_fused_naive
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssd_scan import ssd_scan
 
 BF16 = jnp.bfloat16
 BIAS_GELU = [EpilogueOp("bias_add", operand="bias"), EpilogueOp("gelu")]
@@ -87,6 +90,49 @@ def test_decode_attention_with_lengths(one_chip):
     text = _compile_text(fn, one_chip, ((4, 16, 128), BF16), kv, kv,
                          ((4,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_decode_attention_gqa_head_64(one_chip):
+    """LFM2-8B-A1B's decode: 32 query heads over 8 KV heads, head 64, at
+    its cell's 256 slots and KV length 384."""
+    def fn(q, k, v, lengths):
+        return decode_attention(q, k, v, lengths=lengths, interpret=False)
+    kv = ((256, 8, 384, 64), BF16)
+    text = _compile_text(fn, one_chip, ((256, 32, 64), BF16), kv, kv,
+                         ((256,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_moe_experts_lfm2_share(one_chip):
+    """The 8 experts one chip holds of an LFM2-8B-A1B layer (d 2048, width
+    1792) over a decode step's 256 tokens."""
+    def fn(x, counts, wi, wg, wo):
+        return moe_experts(x, counts, wi, wg, wo, interpret=False)
+    e, c, d, f = 8, 256, 2048, 1792
+    text = _compile_text(fn, one_chip, ((e, c, d), BF16), ((e,), jnp.int32),
+                         ((e, d, f), BF16), ((e, d, f), BF16),
+                         ((e, f, d), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_scan_mamba2(one_chip):
+    """mamba2-780m's heads (48 of 64 wide, state 128) over 2048 steps."""
+    def fn(x, dt, a, b, c):
+        return ssd_scan(x, dt, a, b, c, chunk=128, interpret=False)
+    bh, l, p, n = 48, 2048, 64, 128
+    text = _compile_text(fn, one_chip, ((bh, l, p), BF16),
+                         ((bh, l), jnp.float32), ((bh, 1), jnp.float32),
+                         ((bh, l, n), BF16), ((bh, l, n), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_attention_unoptimized(one_chip):
+    """The optimizer's starting point for flash attention, at OLMo's heads
+    over 1024 positions."""
+    def fn(q, k, v):
+        return attention_unoptimized(q, k, v, causal=True, interpret=False)
+    qkv = ((1, 16, 1024, 128), BF16)
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, qkv, qkv, qkv)
 
 
 def test_rmsnorm(one_chip):
